@@ -149,7 +149,8 @@ def kernel_score_fn() -> ScoreFn:
     Same contract as :func:`candidate_scores` (arbitrary batch dims) — the
     kernel wants flat (T, K)/(T, n) operands, so batch dims are collapsed
     around the call. On TPU each touched row streams HBM→VMEM exactly once;
-    elsewhere the kernel runs in interpret mode (see repro.kernels.ops).
+    on the CPU backend the kernel runs in interpret mode (see
+    repro.kernels.ops).
     """
     from repro.kernels import ops
 

@@ -1,9 +1,10 @@
 """jit'd wrappers for the Pallas kernels with interpret-mode dispatch.
 
-On this CPU container kernels run with ``interpret=True`` (the Pallas
-interpreter executes the kernel body on CPU for correctness); on TPU the same
-call sites compile to Mosaic. ``use_pallas(False)`` routes everything to the
-pure-jnp references (repro.kernels.ref) for A/B testing.
+On a TPU the kernels compile to Mosaic. On the CPU backend they run with
+``interpret=True`` (the Pallas interpreter executes the kernel body, for
+correctness tests); any other platform is an error, never a silent
+interpreter run. ``use_pallas(False)`` routes everything to the pure-jnp
+references (repro.kernels.ref) for A/B testing.
 
 The ``use_pallas`` flag is read at *call* time and passed into the jitted
 impls as a static argument: each setting gets its own jit cache entry, so
@@ -32,7 +33,12 @@ def use_pallas(on: bool):
 
 def _interpret() -> bool:
     if _STATE["interpret"] is None:
-        _STATE["interpret"] = jax.devices()[0].platform != "tpu"
+        platform = jax.devices()[0].platform
+        if platform not in ("tpu", "cpu"):
+            raise RuntimeError(
+                f"Pallas kernels compile for TPU and are interpreted on CPU; "
+                f"no path exists for platform {platform!r}")
+        _STATE["interpret"] = platform == "cpu"
     return _STATE["interpret"]
 
 

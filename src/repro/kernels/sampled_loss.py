@@ -22,10 +22,12 @@ adds is the single-streaming row pipeline. Masking and the per-unique-row
 deduplication live outside (``repro.optim.sparse.accumulate_rows``): they
 are O(T) / O(T·m) and independent of K and C.
 
-Grid: (T / blk_t,); ids arrive via scalar prefetch (SMEM). Each grid step
-loads its h block into VMEM, gathers its blk_t·m rows into a VMEM scratch
-(dynamic row loads from the HBM-resident table), then runs the vectorized
-block math (VPU elementwise + one (blk_t·m, K) contraction for dh).
+Grid: (T / blk_t, blk_t·m / 8); ids arrive via scalar prefetch (SMEM). The
+inner axis gathers the block's blk_t·m rows into a slot-major fp32 VMEM
+scratch (m, blk_t, K) the way :mod:`repro.kernels.gather_scores` does; its
+last step runs the block math on the VPU: one row·h reduction per slot, the
+(blk_t, m) loss tiles, and dh as a coefficient-weighted sum of the m row
+tiles. The biases are one scalar per row and are gathered in XLA.
 """
 from __future__ import annotations
 
@@ -36,6 +38,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.gather_scores import (GROUP, pad_tokens, row_tile_specs,
+                                         stash_rows, token_block)
 
 # Head kinds with a sampled candidate set (everything except `softmax`).
 SAMPLED_KINDS = ("uniform_ns", "freq_ns", "adversarial_ns", "nce",
@@ -121,42 +126,33 @@ def loss_and_coeffs(scores, slot_logp, acc_hit, *, kind: str,
     return loss, g * chain, xi
 
 
-def _kernel(ids_ref, w_ref, b_ref, h_ref, lp_ref, hit_ref, loss_ref,
-            coeff_ref, xi_ref, dh_ref, rows_ref, brow_ref, *, blk_t: int,
-            m: int, kind: str, num_labels: int, reg: float, softcap: float,
-            mask_accidental: bool):
-    it = pl.program_id(0)
-    h = h_ref[...].astype(jnp.float32)                 # (blk_t, K)
+def _kernel(ids_ref, *refs, blk_t: int, m: int, kind: str, num_labels: int,
+            reg: float, softcap: float, mask_accidental: bool):
+    tile_refs = refs[:GROUP]
+    (bias_ref, h_ref, lp_ref, hit_ref, loss_ref, coeff_ref, xi_ref, dh_ref,
+     rows_ref) = refs[GROUP:]
+    it, j = pl.program_id(0), pl.program_id(1)
+    # Each touched row comes HBM→VMEM once; everything downstream reads
+    # the VMEM-resident copy — the row never round-trips.
+    stash_rows(ids_ref, tile_refs, rows_ref, it * blk_t * m)
 
-    # Stream each touched row HBM→VMEM once; everything downstream reads
-    # the VMEM-resident copy (scores on the MXU, dh on the MXU, loss/coeff
-    # on the VPU) — the row never round-trips.
-    def body(j, _):
-        row_id = ids_ref[it * blk_t * m + j]
-        pl.store(rows_ref, (pl.dslice(j, 1), slice(None)),
-                 pl.load(w_ref, (pl.dslice(row_id, 1), slice(None))
-                         ).astype(jnp.float32))
-        pl.store(brow_ref, (pl.dslice(j // m, 1), pl.dslice(j % m, 1)),
-                 pl.load(b_ref, (pl.dslice(row_id, 1),)
-                         ).astype(jnp.float32)[:, None])
-        return 0
-
-    jax.lax.fori_loop(0, blk_t * m, body, 0)
-
-    rows = rows_ref[...].reshape(blk_t, m, rows_ref.shape[-1])
-    scores = jax.lax.dot_general(                      # (blk_t, m)
-        rows, h, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32) + brow_ref[...]
-    loss, coeff, xi = loss_and_coeffs(
-        scores, lp_ref[...].astype(jnp.float32), hit_ref[...] != 0,
-        kind=kind, num_labels=num_labels, reg=reg, softcap=softcap,
-        mask_accidental=mask_accidental)
-    loss_ref[...] = loss[:, None]
-    coeff_ref[...] = coeff
-    xi_ref[...] = xi
-    dh_ref[...] = jax.lax.dot_general(
-        coeff[:, None, :], rows, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)[:, 0, :]
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _block():
+        h = h_ref[...].astype(jnp.float32)             # (blk_t, K)
+        scores = jnp.concatenate(
+            [jnp.sum(rows_ref[c] * h, axis=-1, keepdims=True)
+             for c in range(m)], axis=-1) + bias_ref[...]
+        loss, coeff, xi = loss_and_coeffs(
+            scores, lp_ref[...], hit_ref[...] != 0, kind=kind,
+            num_labels=num_labels, reg=reg, softcap=softcap,
+            mask_accidental=mask_accidental)
+        loss_ref[...] = loss[:, None]
+        coeff_ref[...] = coeff
+        xi_ref[...] = xi
+        dh = coeff[:, 0:1] * rows_ref[0]
+        for c in range(1, m):
+            dh = dh + coeff[:, c:c + 1] * rows_ref[c]
+        dh_ref[...] = dh
 
 
 def sampled_head_loss(w, b, h, ids, slot_logp, *, kind: str,
@@ -169,43 +165,26 @@ def sampled_head_loss(w, b, h, ids, slot_logp, *, kind: str,
     """
     t, k = h.shape
     m = ids.shape[-1]
-    blk_t = min(blk_t, t)
-    pad = (-t) % blk_t
-    if pad:
-        # Padding tokens score row 0 against h = 0; their outputs are
-        # sliced off below (the caller's mask never sees them).
-        h = jnp.concatenate([h, jnp.zeros((pad, k), h.dtype)], axis=0)
-        ids = jnp.concatenate([ids, jnp.zeros((pad, m), ids.dtype)], axis=0)
-        slot_logp = jnp.concatenate(
-            [slot_logp, jnp.zeros((pad, m), slot_logp.dtype)], axis=0)
-    t_pad = t + pad
+    blk_t = token_block(t, m, blk_t)
     ids = ids.astype(jnp.int32)
+    h, ids, slot_logp = pad_tokens(blk_t, h, ids,
+                                   slot_logp.astype(jnp.float32))
+    t_pad = h.shape[0]
     acc_hit = (ids == ids[:, :1]).astype(jnp.int32)
     acc_hit = acc_hit.at[:, 0].set(0)
 
     kernel = functools.partial(
         _kernel, blk_t=blk_t, m=m, kind=kind, num_labels=num_labels,
         reg=reg, softcap=softcap, mask_accidental=mask_accidental)
+    tok = pl.BlockSpec((blk_t, m), lambda it, j, ids: (it, 0))
+    wide = pl.BlockSpec((blk_t, k), lambda it, j, ids: (it, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(t_pad // blk_t,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),      # w stays in HBM
-            pl.BlockSpec(memory_space=pltpu.ANY),      # b stays in HBM
-            pl.BlockSpec((blk_t, k), lambda it, ids: (it, 0)),
-            pl.BlockSpec((blk_t, m), lambda it, ids: (it, 0)),
-            pl.BlockSpec((blk_t, m), lambda it, ids: (it, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((blk_t, 1), lambda it, ids: (it, 0)),
-            pl.BlockSpec((blk_t, m), lambda it, ids: (it, 0)),
-            pl.BlockSpec((blk_t, m), lambda it, ids: (it, 0)),
-            pl.BlockSpec((blk_t, k), lambda it, ids: (it, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((blk_t * m, k), jnp.float32),   # gathered rows
-            pltpu.VMEM((blk_t, m), jnp.float32),       # gathered biases
-        ],
+        grid=(t_pad // blk_t, blk_t * m // GROUP),
+        in_specs=row_tile_specs(w, blk_t * m) + [tok, wide, tok, tok],
+        out_specs=[pl.BlockSpec((blk_t, 1), lambda it, j, ids: (it, 0)),
+                   tok, tok, wide],
+        scratch_shapes=[pltpu.VMEM((m, blk_t, k), jnp.float32)],
     )
     loss, coeff, xi, dh = pl.pallas_call(
         kernel,
@@ -216,6 +195,9 @@ def sampled_head_loss(w, b, h, ids, slot_logp, *, kind: str,
             jax.ShapeDtypeStruct((t_pad, m), jnp.float32),
             jax.ShapeDtypeStruct((t_pad, k), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(ids.reshape(-1), w, b, h, slot_logp.astype(jnp.float32), acc_hit)
+    )(ids.reshape(-1), *([w] * GROUP), b[ids].astype(jnp.float32), h,
+      slot_logp, acc_hit)
     return loss[:t, 0], coeff[:t], xi[:t], dh[:t]

@@ -8,11 +8,19 @@ recursion for a C_blk-leaf subtree in VMEM.
 
 Key structural insight (TPU adaptation of the pointer-walking CPU code):
 for an ALIGNED contiguous leaf block [c0, c0 + C_blk), the ancestry factors
-as   logp(leaf) = prefix(x, c0)  +  subtree-recursion(x, nodes of block),
-where the prefix chain has depth - log2(C_blk) nodes whose indices are
-affine in the block id (dynamic-slice loads), and the subtree nodes occupy
-one contiguous range per level (C_blk - 1 rows total). Grid: (B/blk_b,
-C/blk_c); VMEM per step ~ blk_b·blk_c + blk_c·k floats.
+as   logp(leaf) = prefix(x, c0)  +  subtree-recursion(x, nodes of block).
+The prefixes of all blocks are the leaves of the tree's top
+depth - log2(C_blk) levels: a small XLA sum over those levels, each
+block's branch log-sigmoid gathered from one matmul of the top nodes,
+done before the kernel. The subtree nodes occupy one contiguous range
+per level. With the node table shifted down one row (row i + 1 holds
+node i), full-tree level L starts at row 2^L, so block ic's nodes at
+subtree level l sit in an aligned run of 2^l rows: one BlockSpec per level fetches them (or the whole
+sublane tile around them while 2^l is below a tile). Each level is scored
+with one matmul and spread over the leaves below its nodes with a 0/1
+matmul, so no step needs a lane interleave.
+
+Grid: (B/blk_b, C/blk_c); VMEM per step ~ blk_b·blk_c + blk_c·k floats.
 """
 from __future__ import annotations
 
@@ -21,49 +29,65 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def _kernel(x_ref, w_ref, b_ref, o_ref, *, depth: int, blk_c: int, k: int):
+def _block_prefixes(w, b, x, pre_depth: int):
+    """(B, 2^pre_depth) log-probs of reaching each aligned leaf block:
+    over the top ``pre_depth`` levels, the sum of the log-sigmoid of the
+    branch the block's path takes at its node on that level."""
+    n_top = (1 << pre_depth) - 1
+    z = jax.lax.dot_general(                           # (B, n_top)
+        x.astype(jnp.float32), w[:n_top].astype(jnp.float32),
+        (((1,), (1,)), ((), ())), precision=_HIGHEST,
+        preferred_element_type=jnp.float32) + b[:n_top].astype(jnp.float32)
+    blk = jnp.arange(1 << pre_depth)
+    prefix = jnp.zeros((x.shape[0], 1 << pre_depth), jnp.float32)
+    for level in range(pre_depth):
+        node = (1 << level) - 1 + (blk >> (pre_depth - level))
+        right = ((blk >> (pre_depth - level - 1)) & 1) == 1
+        z_blk = z[:, node]
+        prefix = prefix + jax.nn.log_sigmoid(jnp.where(right, z_blk, -z_blk))
+    return prefix
+
+
+def _kernel(x_ref, prefix_ref, *refs, pre_depth: int, sub_depth: int):
+    *level_refs, o_ref = refs
+    w_refs, b_refs = level_refs[:sub_depth], level_refs[sub_depth:]
     ic = pl.program_id(1)
     x = x_ref[...].astype(jnp.float32)                 # (blk_b, k)
-    blk_b = x.shape[0]
-    sub_depth = blk_c.bit_length() - 1                 # levels inside block
-    pre_depth = depth - sub_depth                      # levels above block
-
-    # ---- prefix chain: ancestors of the whole leaf block ----
-    prefix = jnp.zeros((blk_b, 1), jnp.float32)
-    for level in range(pre_depth):
-        # Node visited at `level` on the path to leaf block `ic`:
-        # idx = 2^level - 1 + (leaf0 >> (depth - level)), leaf0 = ic*blk_c.
-        idx = (1 << level) - 1 + (ic * blk_c >> (depth - level))
-        w_row = pl.load(w_ref, (pl.dslice(idx, 1), slice(None)))   # (1,k)
-        b_val = pl.load(b_ref, (pl.dslice(idx, 1),))
-        z = (jax.lax.dot_general(
-            x, w_row.astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) + b_val)           # (blk_b,1)
-        bit = (ic * blk_c >> (depth - 1 - level)) & 1
-        zeta = jnp.where(bit == 1, 1.0, -1.0)
-        prefix = prefix + jax.nn.log_sigmoid(zeta * z)
-
-    # ---- in-block recursion over sub_depth levels ----
-    logp = jnp.broadcast_to(prefix, (blk_b, 1))
-    for level in range(sub_depth):
-        n_lvl = 1 << level
-        # Within full tree: nodes [2^L - 1 + ic*blk_c/2^(sub_depth-level),
-        # + n_lvl) with L = pre_depth + level.
-        full_level = pre_depth + level
-        base = (1 << full_level) - 1 + ic * n_lvl
-        w_lvl = pl.load(w_ref, (pl.dslice(base, n_lvl), slice(None)))
-        b_lvl = pl.load(b_ref, (pl.dslice(base, n_lvl),))
-        z = (jax.lax.dot_general(
-            x, w_lvl.astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) + b_lvl)   # (blk_b, n_lvl)
-        left = logp + jax.nn.log_sigmoid(-z)
-        right = logp + jax.nn.log_sigmoid(z)
-        logp = jnp.stack([left, right], axis=-1).reshape(blk_b, 2 * n_lvl)
-
+    blk_c = 1 << sub_depth
+    leaf = jax.lax.broadcasted_iota(jnp.int32, (1, blk_c), 1)
+    logp = jnp.broadcast_to(prefix_ref[0], (x.shape[0], blk_c))
+    for level, (w_ref, b_ref) in enumerate(zip(w_refs, b_refs)):
+        span = w_ref.shape[0]
+        shift = sub_depth - level
+        # Row of the block's first level node within the fetched span.
+        first = ((1 << (pre_depth + level)) + ic * (1 << level)) % span
+        z = jax.lax.dot_general(                       # (blk_b, span)
+            x, w_ref[...].astype(jnp.float32), (((1,), (1,)), ((), ())),
+            precision=_HIGHEST, preferred_element_type=jnp.float32
+        ) + b_ref[...].astype(jnp.float32)
+        node = jax.lax.broadcasted_iota(jnp.int32, (span, blk_c), 0)
+        below = jax.lax.broadcasted_iota(jnp.int32, (span, blk_c), 1)
+        spread = (node == first + (below >> shift)).astype(jnp.float32)
+        z_leaf = jax.lax.dot_general(                  # (blk_b, blk_c)
+            z, spread, (((1,), (0,)), ((), ())),
+            precision=_HIGHEST, preferred_element_type=jnp.float32)
+        right = ((leaf >> (shift - 1)) & 1) == 1
+        logp = logp + jax.nn.log_sigmoid(jnp.where(right, z_leaf, -z_leaf))
     o_ref[...] = logp
+
+
+def _level_specs(pre_depth: int, level: int, span: int, k: int):
+    """BlockSpecs of the ``span`` shifted-table rows (and their biases)
+    that hold leaf block ic's nodes at subtree level ``level``."""
+    def at(ic):
+        return ((1 << (pre_depth + level)) + ic * (1 << level)) // span
+
+    return (pl.BlockSpec((span, k), lambda ib, ic: (at(ic), 0)),
+            pl.BlockSpec((None, 1, span), lambda ib, ic: (at(ic), 0, 0)))
 
 
 def tree_logprob_all(w, b, x, *, blk_b: int = 128, blk_c: int = 256,
@@ -77,18 +101,38 @@ def tree_logprob_all(w, b, x, *, blk_b: int = 128, blk_c: int = 256,
     blk_c = min(blk_c, c_pad)
     blk_b = min(blk_b, bsz)
     assert c_pad % blk_c == 0 and bsz % blk_b == 0
-    assert (blk_c & (blk_c - 1)) == 0, "blk_c must be a power of two"
+    assert (blk_c & (blk_c - 1)) == 0 and blk_c >= 2, \
+        "blk_c must be a power of two >= 2"
+    sub_depth = blk_c.bit_length() - 1
+    pre_depth = depth - sub_depth
 
-    kernel = functools.partial(_kernel, depth=depth, blk_c=blk_c, k=k)
+    # (n_blk, B, 1): one prefix per leaf block, broadcast over its leaves.
+    prefix = _block_prefixes(w, b, x, pre_depth).T[:, :, None]
+
+    # Node table shifted one row down (and padded to whole sublane tiles):
+    # copies w, which is C·k floats against the kernel's B·C output.
+    tile = 32 // w.dtype.itemsize
+    rows = max(c_pad, tile)
+    w_rows = jnp.pad(w, ((1, rows - c_pad), (0, 0)))
+    b_rows = jnp.pad(b, (1, rows - c_pad))
+    w_specs, b_specs, b_views = [], [], []
+    for level in range(sub_depth):
+        span = max(1 << level, tile)
+        w_spec, b_spec = _level_specs(pre_depth, level, span, k)
+        w_specs.append(w_spec)
+        b_specs.append(b_spec)
+        b_views.append(b_rows.reshape(rows // span, 1, span))
+
+    kernel = functools.partial(_kernel, pre_depth=pre_depth,
+                               sub_depth=sub_depth)
     return pl.pallas_call(
         kernel,
         grid=(bsz // blk_b, c_pad // blk_c),
         in_specs=[
             pl.BlockSpec((blk_b, k), lambda ib, ic: (ib, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),   # full node table (HBM)
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ],
+            pl.BlockSpec((1, blk_b, 1), lambda ib, ic: (ic, ib, 0)),
+        ] + w_specs + b_specs,
         out_specs=pl.BlockSpec((blk_b, blk_c), lambda ib, ic: (ib, ic)),
         out_shape=jax.ShapeDtypeStruct((bsz, c_pad), jnp.float32),
         interpret=interpret,
-    )(x, w, b)
+    )(x, prefix, *([w_rows] * sub_depth), *b_views)

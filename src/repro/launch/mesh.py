@@ -7,22 +7,23 @@ the host-device count before first jax init.
 from __future__ import annotations
 
 import jax
-
-from repro.parallel.compat import AxisType, make_mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model_axis: int = 1):
-    """Small mesh over whatever devices exist (tests / CPU runs)."""
+    """(data, model) mesh over every local device: the chips of one
+    TPU host, or the CPU backend's (possibly fake) devices."""
     n = jax.device_count()
     assert n % model_axis == 0
-    return make_mesh((n // model_axis, model_axis), ("data", "model"),
-                     axis_types=(AxisType.Auto, AxisType.Auto))
+    return jax.make_mesh((n // model_axis, model_axis), ("data", "model"),
+                         axis_types=(AxisType.Auto, AxisType.Auto))
 
 
 # Hardware constants (TPU v5e), used by the roofline analysis.

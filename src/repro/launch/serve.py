@@ -26,6 +26,13 @@ EOS / max-length retirement (``--eos-id``) with page reclamation, and the
 prefix-keyed candidate cache on the beam path. ``--lockstep`` restores the
 fixed-batch loop (still with EOS handling) for A/B comparison; the two
 emit identical tokens.
+
+``main(argv)`` returns the run's result (engine path: each request's
+status, tokens and error). A request that ends with status "error" while
+no fault plan is installed (``repro.resilience.faults``) is a failure:
+the launcher prints each error and exits non-zero. Run as a script, it
+keeps JAX's persistent compilation cache in ``$JAX_COMPILATION_CACHE_DIR``
+or else ``<checkout>/.jax_cache``.
 """
 from __future__ import annotations
 
@@ -39,6 +46,7 @@ import numpy as np
 from repro import configs as cfg_lib
 from repro.models import lm_head, transformer
 from repro.parallel import cache_shardings, params_shardings
+from repro.resilience import faults
 from repro.train import make_prefill, make_serve_step
 
 
@@ -99,6 +107,7 @@ def run_lockstep(args, cfg, mesh, params, head_state, hcfg):
     print(f"decode {steps} steps: {dt*1e3:.0f} ms "
           f"({real/dt:.1f} tok/s) [{path}, lock-step]")
     print("sample:", out[0].tolist())
+    return {"tokens": out.tolist()}
 
 
 def run_engine(args, cfg, mesh, params, head_state, hcfg):
@@ -170,9 +179,13 @@ def run_engine(args, cfg, mesh, params, head_state, hcfg):
     if metrics_server is not None:
         metrics_server.close()
     print("sample:", handles[0].result().tolist())
+    return {"wall_s": dt,
+            "requests": [{"id": h.request_id, "status": h.status,
+                          "tokens": list(h.tokens), "error": h.error}
+                         for h in handles]}
 
 
-def main():
+def main(argv=None) -> dict:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="stablelm-3b",
                     choices=list(cfg_lib.ARCHS))
@@ -246,7 +259,7 @@ def main():
                     help="abort queued/active requests whose deadline_s "
                          "expired (status='deadline'), reclaiming their "
                          "lanes and pages")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     from repro.launch.mesh import make_host_mesh
     mesh = make_host_mesh(model_axis=args.model_axis)
@@ -261,10 +274,18 @@ def main():
     hcfg = lm_head.head_config(cfg, args.head)
 
     if args.lockstep:
-        run_lockstep(args, cfg, mesh, params, head_state, hcfg)
-    else:
-        run_engine(args, cfg, mesh, params, head_state, hcfg)
+        return run_lockstep(args, cfg, mesh, params, head_state, hcfg)
+    result = run_engine(args, cfg, mesh, params, head_state, hcfg)
+    failed = [r for r in result["requests"] if r["status"] == "error"]
+    if failed and faults.active() is None:
+        for r in failed:
+            print(f"request {r['id']} failed: {r['error']}", flush=True)
+        raise SystemExit(f"{len(failed)} of {len(result['requests'])} "
+                         f"requests failed with no fault plan installed")
+    return result
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -1,10 +1,12 @@
 """Training launcher: pjit the train step onto a mesh and run the
 fault-tolerant loop.
 
-On a TPU cluster this is the per-host entry point (jax.distributed +
-make_production_mesh); on this CPU container it runs reduced configs on a
-host mesh (set XLA_FLAGS=--xla_force_host_platform_device_count=8 to
-exercise real multi-device sharding).
+On a TPU host it trains on every local chip (``--model-axis`` of them per
+model replica); on the CPU backend it runs reduced configs on a host mesh
+(set XLA_FLAGS=--xla_force_host_platform_device_count=8 to exercise real
+multi-device sharding). Run as a script, it keeps JAX's persistent
+compilation cache in ``$JAX_COMPILATION_CACHE_DIR`` or else
+``<checkout>/.jax_cache``.
 
   PYTHONPATH=src python -m repro.launch.train --arch stablelm-3b --reduced \
       --steps 20 --head adversarial_ns --ckpt /tmp/ckpt
@@ -12,6 +14,7 @@ exercise real multi-device sharding).
 from __future__ import annotations
 
 import argparse
+import time
 
 import jax
 import jax.numpy as jnp
@@ -26,19 +29,31 @@ from repro.train import (LoopConfig, init_train_state, make_train_step,
 from repro.train.generator_fit import make_gen_fit_fn
 
 
+# Default learning rates. Adagrad's first step moves every weight by the
+# full rate; at the published widths 0.05 drives mamba2-370m's loss from
+# 1.64 to 12.96 within four steps on a TPU v5e, where 0.005 keeps it
+# between 1.1 and 1.7 (PERF.md).
+REDUCED_LR = 0.05
+FULL_LR = 0.005
+# Seeds of the synthetic data stream and of run_loop's step-indexed rng.
+DATA_SEED, LOOP_SEED = 0, 1
+
+
 def build(args):
     cfg = (cfg_lib.reduced_config(args.arch) if args.reduced
            else cfg_lib.get_config(args.arch))
     hcfg = lm_head.head_config(cfg, args.head, n_neg=args.n_neg,
                                reg=args.reg)
-    opt = OptimizerConfig(name=args.optimizer, learning_rate=args.lr,
+    lr = args.lr if args.lr is not None else (
+        REDUCED_LR if args.reduced else FULL_LR)
+    opt = OptimizerConfig(name=args.optimizer, learning_rate=lr,
                           clip_norm=1.0,
                           head_name=args.head_optimizer,
                           state_dtype=args.state_dtype)
     return cfg, hcfg, opt
 
 
-def main():
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="stablelm-3b",
                     choices=list(cfg_lib.ARCHS))
@@ -48,7 +63,9 @@ def main():
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=64)
-    ap.add_argument("--lr", type=float, default=0.05)
+    ap.add_argument("--lr", type=float, default=None,
+                    help=f"learning rate (default {REDUCED_LR} on the "
+                         f"reduced configs, {FULL_LR} with --full)")
     ap.add_argument("--reg", type=float, default=1e-4)
     ap.add_argument("--n-neg", type=int, default=1)
     ap.add_argument("--head-update", default="auto",
@@ -127,7 +144,22 @@ def main():
                     help="capture a jax.profiler trace of a few "
                          "steady-state steps into this directory (host "
                          "spans annotate the timeline)")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
+
+
+def main(argv=None, gen_fit_fn=None) -> dict:
+    """Parse ``argv`` (default: ``sys.argv``), train, and return the
+    run's result: per-step losses and step metrics, the first step's
+    seconds (which hold the compile), the run's wall seconds, the
+    installed generator's fit step, the resilience counters, and the
+    final ``TrainState``.
+
+    ``gen_fit_fn(state) -> LMHeadState`` replaces the launcher's
+    generator fit (``--gen-warmup`` still sets when it runs): a caller
+    that compares two runs installs one fitted generator in both.
+    """
+    args = parse_args(argv)
+    t_start = time.perf_counter()
 
     from repro.launch.mesh import make_host_mesh
     mesh = make_host_mesh(model_axis=args.model_axis)
@@ -139,7 +171,8 @@ def main():
                                                                state))
     state = jax.device_put(state, state_sh)
 
-    make = lm_batch_fn(cfg.vocab_size, args.batch, args.seq, seed=0)
+    make = lm_batch_fn(cfg.vocab_size, args.batch, args.seq,
+                       seed=DATA_SEED)
     batch_abs = jax.eval_shape(lambda: {k: jnp.asarray(v)
                                         for k, v in make(0).items()})
     batch_sh = batch_shardings(cfg, mesh, batch_abs)
@@ -185,7 +218,7 @@ def main():
     gen_cb = None
     if args.gen_warmup and args.head in ("adversarial_ns", "nce",
                                          "sampled_softmax", "freq_ns"):
-        gen_cb = make_gen_fit_fn(
+        gen_cb = gen_fit_fn or make_gen_fit_fn(
             cfg, lambda s: {k: jnp.asarray(v) for k, v in make(s).items()},
             kind=args.head, max_tokens=8192, method=args.gen_method)
 
@@ -209,24 +242,42 @@ def main():
                       gen_fit_retries=args.gen_fit_retries,
                       gen_fit_timeout_s=args.gen_fit_timeout)
     from repro.obs import Registry, console_summary
-    registry = (Registry() if (args.metrics_jsonl or args.profile_dir)
-                else None)
+    registry = Registry()
+    step_metrics = []
+
+    def on_step(step, m):
+        step_metrics.append({k: m[k] for k in (
+            "loss", "grad_norm", "pos_score", "neg_score") if k in m})
+        print(f"step {step:4d} loss={m['loss']:.4f} "
+              f"{m['step_time']*1e3:.0f}ms", flush=True)
+
     state, hist = run_loop(
-        state, train_step, batch_fn, loop, jax.random.PRNGKey(1),
-        gen_fit_fn=gen_cb, registry=registry,
-        on_step=lambda s, m: print(
-            f"step {s:4d} loss={m['loss']:.4f} "
-            f"{m['step_time']*1e3:.0f}ms", flush=True))
+        state, train_step, batch_fn, loop, jax.random.PRNGKey(LOOP_SEED),
+        gen_fit_fn=gen_cb, registry=registry, on_step=on_step)
     print(f"final loss {hist['loss'][-1]:.4f}; "
           f"stragglers={hist['stragglers']}")
-    if registry is not None:
+    result = {
+        "losses": hist["loss"],
+        "step_metrics": step_metrics,
+        "first_step_s": hist.get("compile_time_s"),
+        "wall_s": time.perf_counter() - t_start,
+        "gen_fit_step": int(jax.device_get(state.gen_fit_step)),
+        "counters": {name: registry.counter(name).value for name in (
+            "genfit/submits", "genfit/refresh_failed",
+            "train/nonfinite_skipped", "train/rollbacks")},
+        "state": state,
+    }
+    if args.metrics_jsonl or args.profile_dir:
         print(console_summary(registry, title="train metrics"))
         if args.metrics_jsonl:
             print(f"metrics JSONL: {args.metrics_jsonl}")
         if args.profile_dir:
             print(f"profile: {args.profile_dir} (load in TensorBoard / "
                   f"xprof; host spans annotate the trace)")
+    return result
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

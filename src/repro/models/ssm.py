@@ -78,7 +78,10 @@ def ssd_chunked(x, dt, a_neg, b_mat, c_mat, d_skip, chunk: int,
     # --- intra-chunk: masked decay-weighted "attention" ---
     rel = seg[:, :, :, None, :] - seg[:, :, None, :, :]   # (B,nc,Q,Q,H)
     tri = jnp.tril(jnp.ones((q, q), bool))
-    l_mat = jnp.where(tri[None, None, :, :, None], jnp.exp(rel), 0.0)
+    # Mask before the exp: above the diagonal rel is a growing positive
+    # sum, exp overflows there, and a mask applied after it turns the
+    # backward pass's 0 * inf into NaN.
+    l_mat = jnp.exp(jnp.where(tri[None, None, :, :, None], rel, -jnp.inf))
     cb = jnp.einsum("bcin,bcjn->bcij", cm, bm)        # (B,nc,Q,Q)
     y_intra = jnp.einsum("bcij,bcijh,bcjhp->bcihp", cb, l_mat, xbar)
 

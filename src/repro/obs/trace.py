@@ -112,9 +112,11 @@ class ProfileWindow:
     ``tick(step)`` starts the device+host trace the first time it is
     called (the loop calls it only from steady-state steps, so XLA
     compilation never pollutes the capture) and stops it after
-    ``n_steps`` ticks. Inert when ``profile_dir`` is falsy or the
-    profiler is unavailable; ``stop()`` is idempotent and always safe to
-    call at loop exit/preemption.
+    ``n_steps`` ticks. Inert when ``profile_dir`` is falsy; when a
+    directory was asked for and the trace cannot start, ``tick`` raises
+    (a run that was meant to be profiled must not finish unprofiled).
+    ``stop()`` is idempotent and always safe to call at loop
+    exit/preemption.
     """
 
     def __init__(self, profile_dir: Optional[str], n_steps: int = 5):
@@ -127,13 +129,9 @@ class ProfileWindow:
         if not self.profile_dir:
             return
         if not self._running and self._ticks == 0:
-            try:
-                import jax.profiler
-                jax.profiler.start_trace(self.profile_dir)
-                self._running = True
-            except Exception:
-                self.profile_dir = None     # profiler unavailable: disarm
-                return
+            import jax.profiler
+            jax.profiler.start_trace(self.profile_dir)
+            self._running = True
         self._ticks += 1
         if self._ticks >= self.n_steps:
             self.stop()

@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.parallel.compat import shard_map, shard_map_norep
 from repro.parallel.sharding import mesh_axes
 
 
@@ -53,7 +52,7 @@ def sharded_candidate_scores(mesh: Mesh, w, b, h, ids):
         scores = jnp.where(mine, scores, 0.0)
         return jax.lax.psum(scores, model)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(model, None), P(model), P(*([None] * h.ndim)),
                   P(*([None] * ids.ndim))),
@@ -129,14 +128,15 @@ def sharded_rows_update(mesh: Mesh, fn, ids, vals, dense_arrays,
     dense_spec = tuple(P(model, *([None] * (d.ndim - 1)))
                        for d in dense_arrays)
     rep_spec = tuple(rep(a) for a in rep_arrays)
-    # norep: the lazy-AdamW catch-up replay (DESIGN.md §11) is a fori_loop,
-    # and `while` has no shard_map replication rule. Replication still
-    # holds by construction: rows carry the model axis, reps are pmax'd.
-    out = shard_map_norep(
+    # check_vma off: the lazy-AdamW catch-up replay (DESIGN.md §11) is a
+    # fori_loop, and `while` has no shard_map replication rule. Replication
+    # still holds by construction: rows carry the model axis, reps are
+    # pmax'd.
+    out = jax.shard_map(
         local, mesh=mesh,
         in_specs=(rep(ids),) + tuple(rep(v) for v in vals) + dense_spec
         + rep_spec,
-        out_specs=dense_spec + rep_spec)(
+        out_specs=dense_spec + rep_spec, check_vma=False)(
         ids, *vals, *dense_arrays, *rep_arrays)
     if extended:
         return out[:n_dense], out[n_dense:]
@@ -188,7 +188,7 @@ def compressed_grad_allreduce(mesh: Mesh, grads_stacked: Any, ef_stacked):
         lambda g: P(dp_axes, *([None] * (g.ndim - 1))), grads_stacked)
     mean_spec = jax.tree.map(
         lambda g: P(*([None] * (g.ndim - 1))), grads_stacked)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(stack_spec, stack_spec),
         out_specs=(mean_spec, stack_spec))(grads_stacked, ef_stacked)

@@ -182,10 +182,11 @@ def paged_cache_shardings(cfg: ModelConfig, mesh: Mesh, cache_abstract: Any,
     """Sharding for the paged KV arena (repro.serve.PagedPool).
 
     K/V pages: any lane gathers any page, so the page dim stays replicated
-    across data axes; the within-page sequence dim goes over 'model',
-    carrying the decode policy above (sharded-S logits, psum'd softmax)
-    into the paged layout. SSM conv/state leaves are lane-indexed and keep
-    the contiguous-cache rules.
+    across data axes; the within-page sequence dim goes over 'model' when
+    TP divides page_len, carrying the decode policy above (sharded-S
+    logits, psum'd softmax) into the paged layout. SSM conv/state leaves
+    are lane-indexed and keep the contiguous-cache rules. Every spec
+    divides its dim, so placing the arena never fails on shape.
     """
     dp_axes, model = mesh_axes(mesh)
     tp = _tp(mesh)
@@ -195,7 +196,8 @@ def paged_cache_shardings(cfg: ModelConfig, mesh: Mesh, cache_abstract: Any,
         names = _path_names(path)
         shape = leaf.shape
         if names[-1] in ("k", "v"):                   # (L,P,page_len,KV,hd)
-            return NamedSharding(mesh, P(None, None, model, None, None))
+            s_ax = model if shape[2] % tp == 0 else None
+            return NamedSharding(mesh, P(None, None, s_ax, None, None))
         if names[-1] == "state":                      # (L,lanes,H,N,P)
             h_ax = model if cfg.ssm_heads % tp == 0 else None
             b_ax = dp_axes if big_batch else None

@@ -196,8 +196,9 @@ class ResultStream:
     ``"ok"`` — completed normally; ``"shed"`` — rejected at submit by the
     bounded admission queue; ``"deadline"`` — aborted past its
     ``deadline_s`` (under ``enforce_deadlines``); ``"error"`` — its
-    prefill raised and the request was failed in isolation. Every
-    non-"ok" terminal sets ``done`` with whatever tokens were produced.
+    prefill raised and the request was failed in isolation, with the
+    exception kept in ``error``. Every non-"ok" terminal sets ``done``
+    with whatever tokens were produced.
     """
 
     def __init__(self, request: Request, request_id: int, now: float):
@@ -206,6 +207,7 @@ class ResultStream:
         self.tokens: List[int] = []
         self.done = False
         self.status = "ok"
+        self.error: Optional[str] = None   # repr of the prefill exception
         self.submitted_at = now
         self.admitted_at: Optional[float] = None
         self.first_token_at: Optional[float] = None
@@ -282,19 +284,13 @@ class Engine:
             # Mesh serving: shard the page arena per the decode policy
             # (page_len over 'model') so each device holds 1/TP of the
             # cache instead of a full replica next to sharded params.
-            # Shapes the mesh cannot divide (jax 0.4 requires exact
-            # divisibility) stay on default placement — GSPMD reshards
-            # inside the step.
             from repro.parallel.sharding import paged_cache_shardings
-            try:
-                self.pool.cache = jax.device_put(
-                    self.pool.cache,
-                    paged_cache_shardings(
-                        cfg, serve_cfg.mesh,
-                        jax.eval_shape(lambda: self.pool.cache),
-                        serve_cfg.n_slots))
-            except ValueError:
-                pass
+            self.pool.cache = jax.device_put(
+                self.pool.cache,
+                paged_cache_shardings(
+                    cfg, serve_cfg.mesh,
+                    jax.eval_shape(lambda: self.pool.cache),
+                    serve_cfg.n_slots))
         beam = serve_cfg.beam
         if beam:
             assert hcfg.kind == "adversarial_ns" and \
@@ -700,7 +696,8 @@ class Engine:
 
     # -- scheduler internals --------------------------------------------
 
-    def _terminate(self, h: ResultStream, status: str, now: float) -> None:
+    def _terminate(self, h: ResultStream, status: str, now: float,
+                   error: Optional[BaseException] = None) -> None:
         """Terminal non-"ok" path shared by deadline aborts and poison
         isolation: reclaim the lane + pages if held, mark the handle
         done, keep the audit counters honest. Refcounted shared pages
@@ -713,6 +710,7 @@ class Engine:
             h.slot = None
         h._spill = None
         h.status = status
+        h.error = None if error is None else repr(error)
         h.done = True
         h.finished_at = now
         if status == "deadline":
@@ -727,7 +725,7 @@ class Engine:
             self.exporter.emit({
                 "event": "request", "request_id": h.request_id,
                 "tokens": len(h.tokens), "priority": h.priority,
-                "status": status,
+                "status": status, "error": h.error,
                 "admission_wait_s": (h.admitted_at - h.submitted_at
                                      if h.admitted_at is not None
                                      else None),
@@ -1026,8 +1024,8 @@ class Engine:
             try:
                 faults.fire("serve/prefill")
                 ok.append(h)
-            except Exception:
-                self._terminate(h, "error", time.perf_counter())
+            except Exception as e:
+                self._terminate(h, "error", time.perf_counter(), e)
         return ok
 
     def _flush_prefill(self, handles: List[ResultStream]) -> None:
@@ -1036,9 +1034,9 @@ class Engine:
             return
         try:
             self._launch_prefill(handles)
-        except Exception:
+        except Exception as e:
             if len(handles) == 1:
-                self._terminate(handles[0], "error", time.perf_counter())
+                self._terminate(handles[0], "error", time.perf_counter(), e)
                 return
             # Poison isolation: the batched launch raised — re-run one
             # request per launch so only the raiser fails and the rest
@@ -1086,9 +1084,9 @@ class Engine:
             return                  # fully-matched prompts: nothing to run
         try:
             self._launch_suffix_prefill(jobs)
-        except Exception:
+        except Exception as e:
             if len(jobs) == 1:
-                self._terminate(jobs[0], "error", time.perf_counter())
+                self._terminate(jobs[0], "error", time.perf_counter(), e)
                 return
             for h in jobs:          # poison isolation, as in the full path
                 self._flush_suffix_prefill([h])

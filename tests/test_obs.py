@@ -184,6 +184,20 @@ def test_profile_window_inert_without_dir():
     p.stop()                                # idempotent
 
 
+def test_profile_window_raises_when_trace_cannot_start(monkeypatch,
+                                                     tmp_path):
+    import jax.profiler
+
+    def refuse(log_dir, *a, **k):
+        raise RuntimeError("profiler already running")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", refuse)
+    p = ProfileWindow(str(tmp_path), n_steps=2)
+    with pytest.raises(RuntimeError, match="profiler already running"):
+        p.tick(0)
+    p.stop()                                # nothing started: no-op
+
+
 # ---------------------------------------------------------------------------
 # Exporters
 # ---------------------------------------------------------------------------

@@ -29,8 +29,7 @@ def run_py(code: str, devices: int = 8, timeout: int = 900):
 def test_sharded_train_step_matches_single_device():
     run_py("""
     import dataclasses, jax, jax.numpy as jnp, numpy as np
-    from repro.parallel import AxisType, ensure_partitionable_rng, make_mesh
-    ensure_partitionable_rng()   # sharded draws == single-device draws
+    from jax.sharding import AxisType
     from repro import configs as cfg_lib
     from repro.data import lm_batch_fn
     from repro.models import lm_head
@@ -53,8 +52,8 @@ def test_sharded_train_step_matches_single_device():
     s1, m1 = jax.jit(step)(state, batch, rng)
 
     # 4x2 mesh
-    mesh = make_mesh((4, 2), ("data", "model"),
-                     axis_types=(AxisType.Auto,) * 2)
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     st_sh = train_state_shardings(cfg, mesh, jax.eval_shape(lambda: state))
     b_sh = batch_shardings(cfg, mesh, jax.eval_shape(lambda: batch))
     state_d = jax.device_put(state, st_sh)
@@ -76,12 +75,12 @@ def test_sharded_train_step_matches_single_device():
 def test_sharded_candidate_scores():
     run_py("""
     import jax, jax.numpy as jnp, numpy as np
-    from repro.parallel import AxisType, make_mesh
+    from jax.sharding import AxisType
     from repro.parallel.collectives import sharded_candidate_scores
     from repro.core.heads import candidate_scores, HeadParams
 
-    mesh = make_mesh((2, 4), ("data", "model"),
-                     axis_types=(AxisType.Auto,) * 2)
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     c, k, t, n = 64, 16, 8, 3
     ks = jax.random.split(jax.random.PRNGKey(0), 4)
     w = jax.random.normal(ks[0], (c, k))
@@ -102,11 +101,11 @@ def test_sharded_rows_update():
     drop; result equals the unsharded gather-update-scatter."""
     run_py("""
     import jax, jax.numpy as jnp, numpy as np
-    from repro.parallel import AxisType, make_mesh
+    from jax.sharding import AxisType
     from repro.parallel.collectives import sharded_rows_update
 
-    mesh = make_mesh((2, 4), ("data", "model"),
-                     axis_types=(AxisType.Auto,) * 2)
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     v, k, u = 64, 8, 7
     w = jax.random.normal(jax.random.PRNGKey(0), (v, k))
     nu = jnp.abs(jax.random.normal(jax.random.PRNGKey(1), (v, k)))
@@ -137,7 +136,7 @@ def test_sparse_train_step_on_mesh():
     sharded TrainState: runs, loss finite, head rows move."""
     run_py("""
     import jax, jax.numpy as jnp, numpy as np
-    from repro.parallel import AxisType, make_mesh
+    from jax.sharding import AxisType
     from repro import configs as cfg_lib
     from repro.data import lm_batch_fn
     from repro.models import lm_head
@@ -145,8 +144,8 @@ def test_sparse_train_step_on_mesh():
     from repro.parallel import batch_shardings, train_state_shardings
     from repro.train import init_train_state, make_train_step
 
-    mesh = make_mesh((2, 4), ("data", "model"),
-                     axis_types=(AxisType.Auto,) * 2)
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     cfg = cfg_lib.reduced_config("stablelm-3b")
     hcfg = lm_head.head_config(cfg, "adversarial_ns", n_neg=2)
     opt = OptimizerConfig(name="adagrad", learning_rate=0.05,
@@ -177,11 +176,11 @@ def test_sparse_train_step_on_mesh():
 def test_compressed_grad_allreduce():
     run_py("""
     import jax, jax.numpy as jnp, numpy as np
-    from repro.parallel import AxisType, make_mesh
+    from jax.sharding import AxisType
     from repro.parallel.collectives import compressed_grad_allreduce
 
-    mesh = make_mesh((4, 2), ("data", "model"),
-                     axis_types=(AxisType.Auto,) * 2)
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     n_dp = 4
     g = {"w": jax.random.normal(jax.random.PRNGKey(0), (n_dp, 32, 8)),
          "b": jax.random.normal(jax.random.PRNGKey(1), (n_dp, 16))}

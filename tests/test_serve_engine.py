@@ -5,7 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.models import lm_head, transformer
 from repro.models.config import ModelConfig
@@ -490,6 +490,26 @@ class TestRetirement:
 # Pool / cache / traffic units
 # ---------------------------------------------------------------------------
 
+class TestPrefillErrors:
+    def test_exception_kept_on_the_handle(self, monkeypatch):
+        """With no fault plan, a prefill that raises (a compile error, an
+        HBM OOM) fails its request with the exception attached."""
+        eng = Engine(CFG, HCFG, PARAMS, HEAD_STATE, ServeConfig(
+            n_slots=N_SLOTS, max_len=MAX_LEN, cache_dtype=jnp.float32))
+
+        def failing_prefill(handles):
+            raise RuntimeError("RESOURCE_EXHAUSTED: prefill does not fit")
+
+        monkeypatch.setattr(eng, "_launch_prefill", failing_prefill)
+        hs = [eng.submit(Request(prompt=np.array([1, 2, 3], np.int32),
+                                 max_new_tokens=2)) for _ in range(2)]
+        eng.run()
+        for h in hs:
+            assert h.done and h.status == "error"
+            assert "RESOURCE_EXHAUSTED" in h.error
+        assert eng.pool.num_free_lanes == N_SLOTS
+
+
 class TestPagedPoolUnit:
     def test_arena_shape(self):
         pool = PagedPool(CFG, 4, 6, 4, 16, dtype=jnp.float32)
@@ -606,9 +626,9 @@ class TestMeshScoring:
     def test_sharded_score_fn_matches_plain(self):
         """make_serve_step(mesh=...) (1-shard mesh here; multi-shard runs in
         test_parallel's subprocess launcher test) == plain scoring."""
-        from repro.parallel import AxisType, make_mesh
-        mesh = make_mesh((1, 1), ("data", "model"),
-                         axis_types=(AxisType.Auto, AxisType.Auto))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((1, 1), ("data", "model"),
+                             axis_types=(AxisType.Auto, AxisType.Auto))
         rng = np.random.default_rng(29)
         b, pl, gen = 2, 3, 3
         prompts = rng.integers(0, CFG.vocab_size, (b, pl)).astype(np.int32)
@@ -620,9 +640,9 @@ class TestMeshScoring:
     def test_engine_mesh_paged_arena_matches(self):
         """Engine(mesh=...) with the paged arena device_put through
         paged_cache_shardings still reproduces the oracle."""
-        from repro.parallel import AxisType, make_mesh
-        mesh = make_mesh((1, 1), ("data", "model"),
-                         axis_types=(AxisType.Auto, AxisType.Auto))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((1, 1), ("data", "model"),
+                             axis_types=(AxisType.Auto, AxisType.Auto))
         rng = np.random.default_rng(31)
         prompts = rng.integers(0, CFG.vocab_size, (2, 3)).astype(np.int32)
         ref = _lockstep(prompts, 3, BEAM)

@@ -12,16 +12,11 @@ Pins the tentpole guarantees:
     head cotangent),
   * global-norm clipping sees the sparse leaves' true norm.
 """
-import sys
-from pathlib import Path
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-sys.path.insert(0, str(Path(__file__).parent))
-from _hypothesis_compat import given, settings, strategies as st  # noqa: E402
+from hypothesis import given, settings, strategies as st
 
 from repro.core import heads as heads_lib
 from repro.core import tree as tree_lib

@@ -4,7 +4,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro import configs as cfg_lib
 from repro.models import moe as moe_lib
@@ -45,6 +45,26 @@ class TestSSD:
                                    np.asarray(y_ref), rtol=2e-4, atol=2e-4)
         np.testing.assert_allclose(np.asarray(s2), np.asarray(s_ref),
                                    rtol=2e-4, atol=2e-4)
+
+    def test_chunked_grads_finite_where_decay_overflows(self):
+        """A long chunk whose summed decay exceeds fp32's exp range: the
+        masked upper triangle must not leak NaN into the gradients, which
+        must match the sequential oracle's."""
+        x, dt, a, bm, cm, d = _ssd_inputs(2, 1, 256, 2, 4, 4)
+        dt = dt + 1.0                       # sum(dt·|A|) over 256 steps > 88
+
+        def loss(fn, x, dt, bm):
+            y, s = fn(x, dt, a, bm, cm, d)
+            return jnp.sum(y ** 2) + jnp.sum(s ** 2)
+
+        chunked = jax.grad(lambda *v: loss(
+            lambda *u: ssm_lib.ssd_chunked(*u, 256), *v), argnums=(0, 1, 2))
+        seq = jax.grad(lambda *v: loss(ssm_lib.ssd_sequential, *v),
+                       argnums=(0, 1, 2))
+        for g, g_ref in zip(chunked(x, dt, bm), seq(x, dt, bm)):
+            assert bool(jnp.all(jnp.isfinite(g)))
+            np.testing.assert_allclose(np.asarray(g), np.asarray(g_ref),
+                                       rtol=1e-3, atol=1e-3)
 
     @settings(max_examples=10, deadline=None)
     @given(seed=st.integers(0, 1000), chunk=st.sampled_from([2, 4, 8]))

@@ -22,15 +22,12 @@ import os
 import subprocess
 import sys
 import textwrap
-from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-sys.path.insert(0, str(Path(__file__).parent))
-from _hypothesis_compat import given, settings, strategies as st  # noqa: E402
+from hypothesis import given, settings, strategies as st
 
 from repro.core import heads as heads_lib
 from repro.core import tree as tree_lib
@@ -471,14 +468,14 @@ class TestShardedState:
     def test_sharded_sm3_and_adamw_match_unsharded(self):
         run_py("""
         import jax, jax.numpy as jnp, numpy as np
-        from repro.parallel import AxisType, make_mesh
+        from jax.sharding import AxisType
         from repro.core.heads import HeadParams
         from repro.optim import OptimizerConfig, apply_updates, \\
             init_opt_state
         from repro.optim.sparse import SparseRows
 
-        mesh = make_mesh((2, 4), ("data", "model"),
-                         axis_types=(AxisType.Auto,) * 2)
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         c, k = 64, 8
         rng = np.random.default_rng(0)
         params = HeadParams(
