@@ -224,17 +224,20 @@ def _sample_candidates(cfg: HeadConfig, gen: Generator, x_gen: jax.Array,
                        sampler: Optional[NegativeSampler] = None):
     """Candidate slots for a sampled strategy: ids (..., 1+n) with the
     positive in slot 0, stop-grad noise log-probs per slot (zeros where the
-    strategy ignores them), and the accidental-hit mask."""
-    neg_ids, neg_logp = sample_negatives(cfg, gen, x_gen, rng, y.shape,
-                                         sampler=sampler)
-    neg_ids = jax.lax.stop_gradient(neg_ids)
-    neg_logp = jax.lax.stop_gradient(neg_logp)
-    need_pos_logp = (cfg.kind in ("nce", "sampled_softmax")
-                     or (cfg.reg and cfg.kind in ("uniform_ns", "freq_ns",
-                                                  "adversarial_ns")))
-    pos_logp = (jax.lax.stop_gradient(
-        noise_log_prob(cfg, gen, x_gen, y, sampler=sampler))
-                if need_pos_logp else jnp.zeros(y.shape, jnp.float32))
+    strategy ignores them), and the accidental-hit mask. Everything that
+    reads the generator runs under the ``sample`` named scope
+    (DESIGN.md §10); the dense, sparse and kernel paths all come here."""
+    with jax.named_scope("sample"):
+        neg_ids, neg_logp = sample_negatives(cfg, gen, x_gen, rng, y.shape,
+                                             sampler=sampler)
+        neg_ids = jax.lax.stop_gradient(neg_ids)
+        neg_logp = jax.lax.stop_gradient(neg_logp)
+        need_pos_logp = (cfg.kind in ("nce", "sampled_softmax")
+                         or (cfg.reg and cfg.kind in (
+                             "uniform_ns", "freq_ns", "adversarial_ns")))
+        pos_logp = (jax.lax.stop_gradient(
+            noise_log_prob(cfg, gen, x_gen, y, sampler=sampler))
+                    if need_pos_logp else jnp.zeros(y.shape, jnp.float32))
     ids = jnp.concatenate([y[..., None], neg_ids], axis=-1)
     slot_logp = jnp.concatenate([pos_logp[..., None], neg_logp], axis=-1)
     acc_hit = jnp.concatenate(

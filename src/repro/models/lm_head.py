@@ -105,26 +105,29 @@ def lm_head_loss(cfg: ModelConfig, hcfg: HeadConfig, params: HeadParams,
     softcapped full-logit path (the O(K·C) baseline the paper replaces).
     ``sampler`` overrides the negative-sampling proposal (a
     ``repro.core.samplers.NegativeSampler``); default derives it from
-    ``hcfg.kind`` + the generator state.
+    ``hcfg.kind`` + the generator state. Runs under the ``head`` named
+    scope, the generator's work under ``head/sample`` (DESIGN.md §10).
     """
-    x_gen = gen_features(state, h)
-    if hcfg.kind == "softmax":
-        logits = masked_full_logits(cfg, params, h)
-        if mask is None:
-            mask = jnp.ones(labels.shape, jnp.float32)
-        logz = jax.nn.logsumexp(logits, axis=-1)
-        pos = jnp.take_along_axis(logits, labels[..., None].astype(jnp.int32),
-                                  axis=-1)[..., 0]
-        denom = jnp.maximum(mask.sum(), 1.0)
-        loss = jnp.sum((logz - pos) * mask) / denom
-        return loss, {"pos_score": jnp.sum(pos * mask) / denom}
-    if score_fn is None:
-        score_fn = (_softcap_score_fn(cfg.final_logit_softcap)
-                    if cfg.final_logit_softcap
-                    else heads_lib.candidate_scores)
-    return heads_lib.head_loss(hcfg, params, state.gen, h, x_gen, labels,
-                               rng, score_fn=score_fn, mask=mask,
-                               sampler=sampler)
+    with jax.named_scope("head"):
+        with jax.named_scope("sample"):
+            x_gen = gen_features(state, h)
+        if hcfg.kind == "softmax":
+            logits = masked_full_logits(cfg, params, h)
+            if mask is None:
+                mask = jnp.ones(labels.shape, jnp.float32)
+            logz = jax.nn.logsumexp(logits, axis=-1)
+            pos = jnp.take_along_axis(
+                logits, labels[..., None].astype(jnp.int32), axis=-1)[..., 0]
+            denom = jnp.maximum(mask.sum(), 1.0)
+            loss = jnp.sum((logz - pos) * mask) / denom
+            return loss, {"pos_score": jnp.sum(pos * mask) / denom}
+        if score_fn is None:
+            score_fn = (_softcap_score_fn(cfg.final_logit_softcap)
+                        if cfg.final_logit_softcap
+                        else heads_lib.candidate_scores)
+        return heads_lib.head_loss(hcfg, params, state.gen, h, x_gen,
+                                   labels, rng, score_fn=score_fn,
+                                   mask=mask, sampler=sampler)
 
 
 def lm_sparse_head_loss(cfg: ModelConfig, hcfg: HeadConfig,
@@ -135,12 +138,15 @@ def lm_sparse_head_loss(cfg: ModelConfig, hcfg: HeadConfig,
     """Sampled-head loss with O(B·K·n_neg) analytic gradients (DESIGN.md
     §8): same loss/metrics stream as :func:`lm_head_loss` (softcap folded
     into the coefficients), plus the deduped ``SparseRows`` head gradient
-    and the trunk cotangent ``dh``. Returns (loss, metrics, sparse, dh)."""
-    x_gen = gen_features(state, h)
-    return heads_lib.sparse_head_loss(
-        hcfg, params, state.gen, h, x_gen, labels.astype(jnp.int32), rng,
-        mask=mask, softcap=cfg.final_logit_softcap, use_kernel=use_kernel,
-        sampler=sampler)
+    and the trunk cotangent ``dh``. Returns (loss, metrics, sparse, dh).
+    Named scopes as in :func:`lm_head_loss`."""
+    with jax.named_scope("head"):
+        with jax.named_scope("sample"):
+            x_gen = gen_features(state, h)
+        return heads_lib.sparse_head_loss(
+            hcfg, params, state.gen, h, x_gen, labels.astype(jnp.int32),
+            rng, mask=mask, softcap=cfg.final_logit_softcap,
+            use_kernel=use_kernel, sampler=sampler)
 
 
 def lm_predictive_topk(cfg: ModelConfig, hcfg: HeadConfig,

@@ -233,6 +233,11 @@ def forward(params, cfg: ModelConfig, tokens: jax.Array,
                              page_table=page_table, write_mask=write_mask)
 
     if cfg.scan_layers:
+        # Under a transform, jax hoists the body's loop-invariant ops (the
+        # SSD's causal mask) out of the scan with only the names opened
+        # inside the body, so the `trunk` scope (DESIGN.md §10) is opened
+        # here as well as around the caller's forward.
+        @jax.named_scope("trunk")
         def scan_body(carry, xs):
             lp, window, cache_l = xs
             attn_mask = (None if masks is None

@@ -477,123 +477,127 @@ def run_loop(state: TrainState, train_step: Callable, batch_fn: Callable,
         with span("train/phase/step", registry):
             state, metrics = train_step(state, batch, sub)
             jax.block_until_ready(metrics["loss"])
-        dt = time.perf_counter() - t0
+        # Host work between steps: the loop synced on the loss, so the
+        # chip waits through all of it (DESIGN.md §10).
+        with span("train/phase/host", registry):
+            dt = time.perf_counter() - t0
 
-        loss = float(jax.device_get(metrics["loss"]))
-        # "nonfinite" is the in-graph skip guard's report (train/step.py
-        # skip_nonfinite): when present and set, the step already
-        # selected its pre-step state and the host sees a clean skip.
-        # A non-finite loss WITHOUT the guard means the optimizer
-        # applied poisoned gradients — only rollback can recover.
-        guarded = "nonfinite" in metrics
-        skipped = guarded and float(
-            jax.device_get(metrics["nonfinite"])) > 0
-        bad = skipped or not np.isfinite(loss)
-        if bad and cfg.nonfinite_policy != "skip":
-            raise FloatingPointError(f"non-finite loss at step {step}")
-        slow = False
-        if is_compile:
-            history["compile_time_s"] = dt
-            registry.gauge("train/compile_time_s").set(dt)
-            emit({"event": "compile", "step": step, "compile_time_s": dt})
-        else:
-            slow = monitor.observe(dt)
-            history["step_times"].append(dt)
-            registry.histogram("train/step_time_s").observe(dt)
-            if slow:
-                registry.counter("train/stragglers").inc()
-        history["loss"].append(loss)
-        history["step"].append(step)
+            loss = float(jax.device_get(metrics["loss"]))
+            # "nonfinite" is the in-graph skip guard's report (train/step.py
+            # skip_nonfinite): when present and set, the step already
+            # selected its pre-step state and the host sees a clean skip.
+            # A non-finite loss WITHOUT the guard means the optimizer
+            # applied poisoned gradients — only rollback can recover.
+            guarded = "nonfinite" in metrics
+            skipped = guarded and float(
+                jax.device_get(metrics["nonfinite"])) > 0
+            bad = skipped or not np.isfinite(loss)
+            if bad and cfg.nonfinite_policy != "skip":
+                raise FloatingPointError(f"non-finite loss at step {step}")
+            slow = False
+            if is_compile:
+                history["compile_time_s"] = dt
+                registry.gauge("train/compile_time_s").set(dt)
+                emit({"event": "compile", "step": step, "compile_time_s": dt})
+            else:
+                slow = monitor.observe(dt)
+                history["step_times"].append(dt)
+                registry.histogram("train/step_time_s").observe(dt)
+                if slow:
+                    registry.counter("train/stragglers").inc()
+            history["loss"].append(loss)
+            history["step"].append(step)
 
-        sample_due = (exporter is not None and not is_compile
-                      and step % max(cfg.metrics_interval, 1) == 0)
-        if on_step is not None or registry.enabled or sample_due:
-            # One host transfer for the whole (tiny, already-computed)
-            # metrics dict, shared by the callback, the gauges, and the
-            # JSONL sample.
-            host_m = {k: float(v)
-                      for k, v in jax.device_get(metrics).items()}
-            snr_ref = (float(jax.device_get(state.snr_ref))
-                       if "snr_ewma" in host_m else None)
-            publish_step_metrics(registry, host_m, snr_ref=snr_ref,
-                                 head_state_bytes=hs_bytes)
-            if sample_due:
-                ev = {"event": "step", "step": step, "loss": loss,
-                      "step_time_s": dt, "straggler": slow}
-                for k in ("snr_proxy", "snr_ewma", "grad_norm"):
-                    if k in host_m:
-                        ev[k] = host_m[k]
-                if snr_ref is not None:
-                    ev["snr_ref"] = snr_ref
-                emit(ev)
-            if on_step is not None:
-                on_step(step, {**host_m, "step_time": dt,
-                               "straggler": slow})
+            sample_due = (exporter is not None and not is_compile
+                          and step % max(cfg.metrics_interval, 1) == 0)
+            if on_step is not None or registry.enabled or sample_due:
+                # One host transfer for the whole (tiny, already-computed)
+                # metrics dict, shared by the callback, the gauges, and the
+                # JSONL sample.
+                host_m = {k: float(v)
+                          for k, v in jax.device_get(metrics).items()}
+                snr_ref = (float(jax.device_get(state.snr_ref))
+                           if "snr_ewma" in host_m else None)
+                publish_step_metrics(registry, host_m, snr_ref=snr_ref,
+                                     head_state_bytes=hs_bytes)
+                if sample_due:
+                    ev = {"event": "step", "step": step, "loss": loss,
+                          "step_time_s": dt, "straggler": slow}
+                    for k in ("snr_proxy", "snr_ewma", "grad_norm"):
+                        if k in host_m:
+                            ev[k] = host_m[k]
+                    if snr_ref is not None:
+                        ev["snr_ref"] = snr_ref
+                    emit(ev)
+                if on_step is not None:
+                    on_step(step, {**host_m, "step_time": dt,
+                                   "straggler": slow})
 
-        if bad:
-            nonfinite_streak += 1
-            registry.counter("train/nonfinite_skipped").inc()
-            history.setdefault("nonfinite_steps", []).append(step)
-            emit({"event": "nonfinite_skip", "step": step,
-                  "streak": nonfinite_streak})
-            if not guarded or nonfinite_streak >= cfg.max_consecutive_nonfinite:
-                # Rollback-restore: rewind to the newest verifiable
-                # checkpoint and replay. The data/rng streams are
-                # step-indexed, so the replay is deterministic; a
-                # persistent cause re-fires and the rollback budget
-                # converts it into the legacy crash.
-                rollbacks += 1
-                ck = (latest_step(cfg.checkpoint_dir)
-                      if cfg.checkpoint_dir else None)
-                if ck is None or rollbacks > cfg.max_rollbacks:
-                    raise FloatingPointError(
-                        f"non-finite loss at step {step} ("
-                        + ("no verifiable checkpoint to roll back to"
-                           if ck is None else
-                           f"rollback budget {cfg.max_rollbacks} "
-                           f"exhausted") + ")")
-                restored, _ = restore_checkpoint(
-                    cfg.checkpoint_dir, state.as_pytree(), step=ck)
-                state = TrainState(**restored)
-                registry.counter("train/rollbacks").inc()
-                history.setdefault("rollback_steps", []).append([step, ck])
-                emit({"event": "rollback_restore", "step": step,
-                      "restored_step": ck})
+            if bad:
+                nonfinite_streak += 1
+                registry.counter("train/nonfinite_skipped").inc()
+                history.setdefault("nonfinite_steps", []).append(step)
+                emit({"event": "nonfinite_skip", "step": step,
+                      "streak": nonfinite_streak})
+                if (not guarded or nonfinite_streak
+                        >= cfg.max_consecutive_nonfinite):
+                    # Rollback-restore: rewind to the newest verifiable
+                    # checkpoint and replay. The data/rng streams are
+                    # step-indexed, so the replay is deterministic; a
+                    # persistent cause re-fires and the rollback budget
+                    # converts it into the legacy crash.
+                    rollbacks += 1
+                    ck = (latest_step(cfg.checkpoint_dir)
+                          if cfg.checkpoint_dir else None)
+                    if ck is None or rollbacks > cfg.max_rollbacks:
+                        raise FloatingPointError(
+                            f"non-finite loss at step {step} ("
+                            + ("no verifiable checkpoint to roll back to"
+                               if ck is None else
+                               f"rollback budget {cfg.max_rollbacks} "
+                               f"exhausted") + ")")
+                    restored, _ = restore_checkpoint(
+                        cfg.checkpoint_dir, state.as_pytree(), step=ck)
+                    state = TrainState(**restored)
+                    registry.counter("train/rollbacks").inc()
+                    history.setdefault("rollback_steps", []).append([step, ck])
+                    emit({"event": "rollback_restore", "step": step,
+                          "restored_step": ck})
+                    nonfinite_streak = 0
+                    refresher, pending_swap = establish_refresh(state, ck)
+                    step = ck
+                    continue
+                # Clean skip (in-graph guard kept the state): fall through —
+                # checkpointing and preemption still see a valid state.
+            else:
                 nonfinite_streak = 0
-                refresher, pending_swap = establish_refresh(state, ck)
-                step = ck
-                continue
-            # Clean skip (in-graph guard kept the state): fall through —
-            # checkpointing and preemption still see a valid state.
-        else:
-            nonfinite_streak = 0
 
-        if snr_mode and gen_fit_fn is not None:
-            # Arm the reference snr_patience steps after the install:
-            # freeze the EWMA as the "healthy" level the trigger compares
-            # against. A running max would false-trigger on a fresh
-            # generator — the proxy naturally decays from its 1/2 optimum
-            # as the discriminator sharpens — so the reference is a fixed
-            # early-window snapshot instead. Runs before maybe_checkpoint
-            # so the armed value is durable and resume replays it.
-            fit_host = int(jax.device_get(state.gen_fit_step))
-            if fit_host >= 0:
-                install_est = (fit_host + cfg.gen_swap_delay
-                               if use_async else fit_host)
-                if (float(jax.device_get(state.snr_ref)) < 0
-                        and float(jax.device_get(state.snr_ewma)) >= 0
-                        and step - install_est >= cfg.snr_patience):
-                    # jnp.copy, not the array itself: snr_ref aliasing
-                    # snr_ewma's buffer breaks donated train steps
-                    # ("attempt to donate the same buffer twice").
-                    state = state._replace(
-                        snr_ref=jnp.copy(state.snr_ewma))
+            if snr_mode and gen_fit_fn is not None:
+                # Arm the reference snr_patience steps after the install:
+                # freeze the EWMA as the "healthy" level the trigger compares
+                # against. A running max would false-trigger on a fresh
+                # generator — the proxy naturally decays from its 1/2 optimum
+                # as the discriminator sharpens — so the reference is a fixed
+                # early-window snapshot instead. Runs before maybe_checkpoint
+                # so the armed value is durable and resume replays it.
+                fit_host = int(jax.device_get(state.gen_fit_step))
+                if fit_host >= 0:
+                    install_est = (fit_host + cfg.gen_swap_delay
+                                   if use_async else fit_host)
+                    if (float(jax.device_get(state.snr_ref)) < 0
+                            and float(jax.device_get(state.snr_ewma)) >= 0
+                            and step - install_est >= cfg.snr_patience):
+                        # jnp.copy, not the array itself: snr_ref aliasing
+                        # snr_ewma's buffer breaks donated train steps
+                        # ("attempt to donate the same buffer twice").
+                        state = state._replace(
+                            snr_ref=jnp.copy(state.snr_ewma))
 
-        maybe_checkpoint(step + 1)
-        if preemption.requested:
-            maybe_checkpoint(step + 1, force=True)
-            history["preempted_at"] = step + 1
-            break
+            maybe_checkpoint(step + 1)
+            if preemption.requested:
+                maybe_checkpoint(step + 1, force=True)
+                history["preempted_at"] = step + 1
+                break
         step += 1
 
     history["stragglers"] = monitor.flagged

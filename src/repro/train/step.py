@@ -25,10 +25,11 @@ from repro.train.state import TrainState, snr_reset_pair
 
 def loss_fn(params, cfg: ModelConfig, hcfg: HeadConfig, head_state,
             batch: Dict[str, jax.Array], rng: jax.Array, sampler=None):
-    h, _, fwd_metrics = transformer.forward(
-        params, cfg, batch["tokens"],
-        positions=batch.get("positions"),
-        vision_embeds=batch.get("vision_embeds"))
+    with jax.named_scope("trunk"):
+        h, _, fwd_metrics = transformer.forward(
+            params, cfg, batch["tokens"],
+            positions=batch.get("positions"),
+            vision_embeds=batch.get("vision_embeds"))
     labels = batch["labels"]
     mask = batch.get("mask")
     if cfg.modality == "vision" and labels.shape[1] != h.shape[1]:
@@ -126,10 +127,15 @@ def make_train_step(cfg: ModelConfig, hcfg: HeadConfig,
         trunk = {k: v for k, v in params.items() if k not in drop}
         tokens = batch["tokens"]
 
+        # The trunk's forward runs inside jax.vjp, so its ops carry
+        # `jvp(trunk)` and their backward `transpose(jvp(trunk))`; the
+        # head scope is opened by lm_head itself.
         if embed_sparse:
             cdt = jnp.dtype(cfg.dtype)
-            h0 = jnp.take(params["embed"], tokens, axis=0).astype(cdt)
+            with jax.named_scope("trunk"):
+                h0 = jnp.take(params["embed"], tokens, axis=0).astype(cdt)
 
+            @jax.named_scope("trunk")
             def trunk_fwd(tp, h0_in):
                 ve = batch.get("vision_embeds")
                 ie = (h0_in if ve is None
@@ -143,6 +149,7 @@ def make_train_step(cfg: ModelConfig, hcfg: HeadConfig,
             h, trunk_vjp, fwd_metrics = jax.vjp(trunk_fwd, trunk, h0,
                                                 has_aux=True)
         else:
+            @jax.named_scope("trunk")
             def trunk_fwd(tp):
                 h, _, fwd_metrics = transformer.forward(
                     tp, cfg, tokens, positions=batch.get("positions"),
@@ -159,60 +166,61 @@ def make_train_step(cfg: ModelConfig, hcfg: HeadConfig,
             cfg, hcfg, HeadParams(**params["head"]), state.head_state,
             h[:, n_vis:] if n_vis else h, labels, rng,
             mask=batch.get("mask"), use_kernel=head_kernel, sampler=sampler)
-        if n_vis:   # vision prefix carries no next-token loss
-            dh = jnp.pad(dh, ((0, 0), (n_vis, 0), (0, 0)))
-        if embed_sparse:
-            trunk_grads, dh0 = trunk_vjp(dh.astype(h.dtype))
-            vocab = params["embed"].shape[0]
-            grads = {**trunk_grads, "head": sparse,
-                     "embed": sparse_opt.accumulate_embed_rows(
-                         tokens.reshape(-1),
-                         dh0.reshape(-1, dh0.shape[-1]), vocab)}
-        else:
-            (trunk_grads,) = trunk_vjp(dh.astype(h.dtype))
-            grads = {**trunk_grads, "head": sparse}
+        with jax.named_scope("trunk"):
+            if n_vis:   # vision prefix carries no next-token loss
+                dh = jnp.pad(dh, ((0, 0), (n_vis, 0), (0, 0)))
+            if embed_sparse:
+                trunk_grads, dh0 = trunk_vjp(dh.astype(h.dtype))
+                vocab = params["embed"].shape[0]
+                grads = {**trunk_grads, "head": sparse,
+                         "embed": sparse_opt.accumulate_embed_rows(
+                             tokens.reshape(-1),
+                             dh0.reshape(-1, dh0.shape[-1]), vocab)}
+            else:
+                (trunk_grads,) = trunk_vjp(dh.astype(h.dtype))
+                grads = {**trunk_grads, "head": sparse}
         metrics = {"loss": loss, **fwd_metrics, **head_metrics}
         return grads, metrics
 
     def train_step(state: TrainState, batch, rng):
-        # named_scope labels the jaxpr/HLO so a --profile-dir device
-        # capture attributes ops to the same phases the host spans use
-        # (DESIGN.md §10): loss+grad (incl. sample-negatives inside the
-        # head loss) vs the optimizer scatter.
-        with jax.named_scope("loss_and_grad"):
-            grads, metrics = (dense_step if mode == "dense"
-                              else sparse_step)(state, batch, rng)
-        with jax.named_scope("optimizer_scatter"):
+        # Three top-level named scopes cover the step (DESIGN.md §10):
+        # `trunk` and `head` (with `head/sample`, the generator tree)
+        # inside the loss and gradient, `optimizer` from here to the end.
+        # Each op's HLO metadata carries its scope, so a device trace's
+        # op time splits by layer.
+        grads, metrics = (dense_step if mode == "dense"
+                          else sparse_step)(state, batch, rng)
+        with jax.named_scope("optimizer"):
             new_params, new_opt, opt_metrics = apply_updates(
                 opt_cfg, state.params, grads, state.opt_state, mesh=mesh)
-        metrics.update(opt_metrics)
-        # Fold the per-batch signal-mass proxy into the EWMA the SNR
-        # refresh trigger watches. "snr_proxy" presence is a trace-time
-        # Python check (the head kind is static), so the dense-softmax
-        # path compiles without the extra arithmetic. snr_ref is armed
-        # host-side by the loop; the step only smooths.
-        snr_ewma = state.snr_ewma
-        if "snr_proxy" in metrics:
-            p = metrics["snr_proxy"].astype(jnp.float32)
-            snr_ewma = jnp.where(
-                state.snr_ewma < 0, p,
-                (1.0 - snr_alpha) * state.snr_ewma + snr_alpha * p)
-            metrics["snr_ewma"] = snr_ewma
-        if skip_nonfinite:
-            ok = jnp.isfinite(metrics["loss"])
-            if "grad_norm" in metrics:
-                ok = ok & jnp.isfinite(metrics["grad_norm"])
-            sel = lambda n, o: jnp.where(ok, n, o)  # noqa: E731
-            new_params = jax.tree.map(sel, new_params, state.params)
-            new_opt = jax.tree.map(sel, new_opt, state.opt_state)
-            snr_ewma = jnp.where(ok, snr_ewma, state.snr_ewma)
-            metrics["nonfinite"] = (~ok).astype(jnp.float32)
-        return TrainState(step=state.step + 1, params=new_params,
-                          opt_state=new_opt,
-                          head_state=state.head_state,
-                          gen_fit_step=state.gen_fit_step,
-                          snr_ewma=snr_ewma,
-                          snr_ref=state.snr_ref), metrics
+            metrics.update(opt_metrics)
+            # Fold the per-batch signal-mass proxy into the EWMA the SNR
+            # refresh trigger watches. "snr_proxy" presence is a trace-time
+            # Python check (the head kind is static), so the dense-softmax
+            # path compiles without the extra arithmetic. snr_ref is armed
+            # host-side by the loop; the step only smooths.
+            snr_ewma = state.snr_ewma
+            if "snr_proxy" in metrics:
+                p = metrics["snr_proxy"].astype(jnp.float32)
+                snr_ewma = jnp.where(
+                    state.snr_ewma < 0, p,
+                    (1.0 - snr_alpha) * state.snr_ewma + snr_alpha * p)
+                metrics["snr_ewma"] = snr_ewma
+            if skip_nonfinite:
+                ok = jnp.isfinite(metrics["loss"])
+                if "grad_norm" in metrics:
+                    ok = ok & jnp.isfinite(metrics["grad_norm"])
+                sel = lambda n, o: jnp.where(ok, n, o)  # noqa: E731
+                new_params = jax.tree.map(sel, new_params, state.params)
+                new_opt = jax.tree.map(sel, new_opt, state.opt_state)
+                snr_ewma = jnp.where(ok, snr_ewma, state.snr_ewma)
+                metrics["nonfinite"] = (~ok).astype(jnp.float32)
+            return TrainState(step=state.step + 1, params=new_params,
+                              opt_state=new_opt,
+                              head_state=state.head_state,
+                              gen_fit_step=state.gen_fit_step,
+                              snr_ewma=snr_ewma,
+                              snr_ref=state.snr_ref), metrics
 
     return train_step
 

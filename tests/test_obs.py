@@ -123,6 +123,18 @@ def test_disabled_registry_hands_out_shared_singletons():
     assert NULL_REGISTRY.enabled is False
 
 
+def _retained_bytes(loop) -> int:
+    loop()                              # warm caches outside the window
+    gc.collect()
+    tracemalloc.start()
+    t0 = tracemalloc.get_traced_memory()[0]
+    loop()
+    gc.collect()
+    t1 = tracemalloc.get_traced_memory()[0]
+    tracemalloc.stop()
+    return t1 - t0
+
+
 def test_disabled_mode_allocates_nothing():
     """The instrumented-every-step train loop relies on disabled mode
     being allocation-free: no instrument objects, no span objects."""
@@ -136,15 +148,21 @@ def test_disabled_mode_allocates_nothing():
             with span("train/phase/step", r):
                 pass
 
-    loop()                              # warm caches outside the window
-    gc.collect()
-    tracemalloc.start()
-    t0 = tracemalloc.get_traced_memory()[0]
-    loop()
-    gc.collect()
-    t1 = tracemalloc.get_traced_memory()[0]
-    tracemalloc.stop()
-    assert t1 - t0 < 512, f"disabled-mode loop retained {t1 - t0} bytes"
+    retained = _retained_bytes(loop)
+    assert retained < 512, f"disabled-mode loop retained {retained} bytes"
+
+
+def test_disabled_host_span_allocates_nothing():
+    """The loop's per-step host span is the shared no-op when disabled."""
+    assert span("train/phase/host", NULL_REGISTRY) is _NULL_SPAN
+
+    def loop():
+        for _ in range(200):
+            with span("train/phase/host", NULL_REGISTRY):
+                pass
+
+    retained = _retained_bytes(loop)
+    assert retained < 512, f"disabled host span retained {retained} bytes"
 
 
 # ---------------------------------------------------------------------------
@@ -342,7 +360,8 @@ def test_train_loop_emits_documented_metrics(tmp_path):
     snap = hist["metrics"]
     for name in ("train/steps", "train/loss", "train/step_time_s",
                  "train/compile_time_s", "train/phase/data",
-                 "train/phase/step", "snr/proxy", "snr/ewma",
+                 "train/phase/step", "train/phase/host", "snr/proxy",
+                 "snr/ewma",
                  "genfit/submits", "genfit/swaps", "genfit/fit_wall_s",
                  "genfit/staleness_at_swap"):
         assert name in snap, f"missing documented metric {name}"

@@ -321,6 +321,22 @@ def test_nonfinite_skip_counts_and_completes(tmp_path):
             if e["event"] == "nonfinite_skip"] == [3]
 
 
+def test_host_span_once_per_executed_step():
+    """``train/phase/host`` (the loop's work after each step's sync) is
+    observed once per executed step, the in-graph skip step included."""
+    cfg, state, step_fn, batch_fn = _setup(seed=2)
+    loop = LoopConfig(total_steps=6, checkpoint_dir=None, log_every=100)
+    plan = FaultPlan([Fault("train/batch", 2, "corrupt")])
+    with faults.install(plan):
+        _, hist = run_loop(state, step_fn, batch_fn, loop,
+                           jax.random.PRNGKey(2), registry=Registry())
+    assert hist["nonfinite_steps"] == [2]
+    snap = hist["metrics"]
+    assert snap["train/phase/host"]["count"] == 6
+    assert snap["train/phase/step"]["count"] == 6
+    assert snap["train/steps"]["value"] == 6
+
+
 def test_rollback_replay_is_bit_equal_to_fault_free(tmp_path):
     """THE tentpole invariant: a corrupt batch that escalates to
     rollback-restore leaves the final parameters bit-identical to an
