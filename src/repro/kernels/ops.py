@@ -16,12 +16,14 @@ from __future__ import annotations
 import functools
 
 import jax
+import jax.numpy as jnp
 
 from repro.kernels import ref as ref_lib
 from repro.kernels.flash_attention import flash_attention as _flash
 from repro.kernels.gather_scores import gather_scores as _gather
 from repro.kernels.sampled_loss import sampled_head_loss as _sampled_loss
 from repro.kernels.segment_scores import segment_stats as _segstats
+from repro.kernels.ssd_intra import LANES, ssd_intra as _ssd_intra
 from repro.kernels.tree_logprob import tree_logprob_all as _treelp
 
 _STATE = {"use_pallas": True, "interpret": None}
@@ -116,3 +118,21 @@ def _segstats_impl(vals, seg, num_segments: int, pallas: bool):
 def segment_stats(vals, seg, num_segments: int):
     """Segment-summed fit statistics (repro.genfit hot reduction)."""
     return _segstats_impl(vals, seg, num_segments, _STATE["use_pallas"])
+
+
+@functools.partial(jax.jit, static_argnames=("pallas",))
+def _ssd_intra_impl(cm, bm, seg, xbar, pallas: bool):
+    if not pallas or seg.shape[2] % LANES:
+        return ref_lib.ssd_intra_ref(cm, bm, seg, xbar)
+    interpret = _interpret()
+    # XLA's default precision for an f32 matmul: one bf16 pass on a TPU,
+    # f32 on the CPU. The kernel's matmuls take the same operands.
+    return _ssd_intra(cm, bm, seg, xbar, interpret,
+                      jnp.float32 if interpret else jnp.bfloat16)
+
+
+def ssd_intra(cm, bm, seg, xbar):
+    """The SSD's intra-chunk block (repro.kernels.ssd_intra), differentiable.
+    A chunk length that is not a multiple of 128 lanes stays on the
+    einsums (repro.kernels.ref.ssd_intra_ref)."""
+    return _ssd_intra_impl(cm, bm, seg, xbar, _STATE["use_pallas"])

@@ -84,3 +84,18 @@ def sampled_head_loss_ref(w, b, h, ids, slot_logp, *, kind: str,
         reg=reg, softcap=softcap, mask_accidental=mask_accidental)
     dh = jnp.einsum("tn,tnk->tk", coeff, w[ids].astype(jnp.float32))
     return loss, coeff, xi, dh
+
+
+def ssd_intra_ref(cm, bm, seg, xbar):
+    """The SSD's intra-chunk block as einsums: cm, bm (B,nc,Q,N), seg
+    (B,nc,Q,H) the within-chunk cumulative dt·A, xbar (B,nc,Q,H,P) ->
+    y_intra (B,nc,Q,H,P). XLA materializes the (Q, Q, H) decay tensor."""
+    q = seg.shape[2]
+    rel = seg[:, :, :, None, :] - seg[:, :, None, :, :]   # (B,nc,Q,Q,H)
+    tri = jnp.tril(jnp.ones((q, q), bool))
+    # Mask before the exp: above the diagonal rel is a growing positive
+    # sum, exp overflows there, and a mask applied after it turns the
+    # backward pass's 0 * inf into NaN.
+    l_mat = jnp.exp(jnp.where(tri[None, None, :, :, None], rel, -jnp.inf))
+    cb = jnp.einsum("bcin,bcjn->bcij", cm, bm)            # (B,nc,Q,Q)
+    return jnp.einsum("bcij,bcijh,bcjhp->bcihp", cb, l_mat, xbar)

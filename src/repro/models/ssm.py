@@ -16,6 +16,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import ops as kernel_ops
 from repro.models.config import ModelConfig
 from repro.models.layers import normal_init, rmsnorm
 
@@ -76,14 +77,7 @@ def ssd_chunked(x, dt, a_neg, b_mat, c_mat, d_skip, chunk: int,
 
     seg = jnp.cumsum(da, axis=2)                      # (B,nc,Q,H)
     # --- intra-chunk: masked decay-weighted "attention" ---
-    rel = seg[:, :, :, None, :] - seg[:, :, None, :, :]   # (B,nc,Q,Q,H)
-    tri = jnp.tril(jnp.ones((q, q), bool))
-    # Mask before the exp: above the diagonal rel is a growing positive
-    # sum, exp overflows there, and a mask applied after it turns the
-    # backward pass's 0 * inf into NaN.
-    l_mat = jnp.exp(jnp.where(tri[None, None, :, :, None], rel, -jnp.inf))
-    cb = jnp.einsum("bcin,bcjn->bcij", cm, bm)        # (B,nc,Q,Q)
-    y_intra = jnp.einsum("bcij,bcijh,bcjhp->bcihp", cb, l_mat, xbar)
+    y_intra = kernel_ops.ssd_intra(cm, bm, seg, xbar)
 
     # --- chunk states ---
     decay_to_end = jnp.exp(seg[:, :, -1:, :] - seg)   # (B,nc,Q,H)

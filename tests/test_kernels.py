@@ -281,3 +281,51 @@ class TestSegmentStats:
                                    rtol=1e-4, atol=1e-5)
         np.testing.assert_array_equal(np.asarray(t_jnp.label_to_leaf),
                                       np.asarray(t_ker.label_to_leaf))
+
+
+def _ssd_block_inputs(q, n, h, p=16, bsz=1, nc=2, dt_shift=0.0, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    dt = jax.nn.softplus(jax.random.normal(ks[0], (bsz, nc, q, h)) - 1.0)
+    dt = dt + dt_shift
+    a_neg = -jnp.exp(0.3 * jax.random.normal(ks[1], (h,)))
+    seg = jnp.cumsum(dt * a_neg, axis=2)
+    xbar = jax.random.normal(ks[2], (bsz, nc, q, h, p)) * dt[..., None]
+    bm = jax.random.normal(ks[3], (bsz, nc, q, n)) / np.sqrt(n)
+    cm = jax.random.normal(ks[4], (bsz, nc, q, n)) / np.sqrt(n)
+    dy = jax.random.normal(ks[5], (bsz, nc, q, h, p))
+    return (cm, bm, seg, xbar), dy
+
+
+@pytest.mark.parametrize("q,n,h,dt_shift,mxu", [
+    (128, 16, 2, 0.0, None), (128, 16, 3, 0.0, None),
+    (128, 128, 2, 0.0, None), (128, 128, 3, 0.0, None),
+    (256, 16, 2, 0.0, None), (256, 16, 3, 0.0, None),
+    (256, 128, 2, 0.0, None), (256, 128, 3, 0.0, None),
+    # dt + 1: the summed decay passes 88 within one chunk, where exp
+    # overflows; the masked triangle must leak no NaN into the gradients.
+    (256, 16, 2, 1.0, None),
+    # The TPU's matmul operands (one bf16 pass), run by the interpreter.
+    (256, 128, 3, 0.0, jnp.bfloat16),
+    # A chunk shorter than the 128 lanes stays on the einsums.
+    (8, 16, 2, 0.0, None),
+])
+def test_ssd_intra_vs_einsums(q, n, h, dt_shift, mxu):
+    """The SSD intra-chunk kernel's output and its gradients with respect
+    to cm, bm, seg and xbar against the einsums it replaces."""
+    from repro.kernels import ops
+    from repro.kernels.ssd_intra import ssd_intra
+
+    args, dy = _ssd_block_inputs(q, n, h, dt_shift=dt_shift)
+    fn = (ops.ssd_intra if mxu is None
+          else lambda *a: ssd_intra(*a, True, mxu))
+    jaxpr = str(jax.make_jaxpr(fn)(*args))
+    assert ("pallas_call" in jaxpr) == (q % 128 == 0)
+    out, vjp = jax.vjp(fn, *args)
+    ref, ref_vjp = jax.vjp(ref_lib.ssd_intra_ref, *args)
+    # bf16 operands: a few of bf16's 2^-8 steps of the largest value.
+    tol = 1e-5 if mxu is None else 4 * 2.0 ** -8
+    for got, want in zip((out,) + vjp(dy), (ref,) + ref_vjp(dy)):
+        assert bool(jnp.all(jnp.isfinite(got)))
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), rtol=tol,
+            atol=tol * float(jnp.max(jnp.abs(want))))

@@ -15,10 +15,12 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro import configs as cfg_lib
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.gather_scores import gather_scores
 from repro.kernels.sampled_loss import SAMPLED_KINDS, sampled_head_loss
 from repro.kernels.segment_scores import segment_stats
+from repro.kernels.ssd_intra import ssd_intra
 from repro.kernels.tree_logprob import tree_logprob_all
 from repro.launch import smoke_shapes
 
@@ -114,3 +116,21 @@ def test_segment_stats(compile_on, which):
     n, d, s = smoke_shapes.segment_stats_shapes()[which]
     compile_on(lambda v, g: segment_stats(v, g, s),
                ((n, d), jnp.float32), ((n,), jnp.int32))
+
+
+@pytest.mark.parametrize("arch", ["mamba2-370m", "hymba-1.5b"])
+@pytest.mark.parametrize("grad", [False, True])
+def test_ssd_intra(compile_on, arch, grad):
+    """The SSD intra-chunk block, forward and backward, at the benchmark
+    cell's step (16 rows of 2,048 tokens, chunk 256): mamba2-370m's
+    32 heads with state 128 and hymba-1.5b's 50 with state 16."""
+    cfg = cfg_lib.get_config(arch)
+    bsz, nc, q = 16, 2048 // cfg.ssm_chunk, cfg.ssm_chunk
+    h, p, n = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
+    fn = ssd_intra
+    if grad:
+        fn = jax.grad(lambda *a: jnp.sum(ssd_intra(*a) * a[3]),
+                      argnums=(0, 1, 2, 3))
+    compile_on(fn, ((bsz, nc, q, n), jnp.float32),
+               ((bsz, nc, q, n), jnp.float32), ((bsz, nc, q, h), jnp.float32),
+               ((bsz, nc, q, h, p), jnp.float32))
